@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device, in %
+(1 - union of the device's operation intervals / window, mean over chips)."""
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s / ctx.trace.window_s)
