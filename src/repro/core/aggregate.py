@@ -21,7 +21,6 @@ class ClientUpdate:
     n_samples: int
     mask: Optional[dict] = None  # None = trained the full model
     sim_time: float = 0.0
-    real_time: float = 0.0
     client_id: int = -1
 
 

@@ -19,13 +19,13 @@ store the server falls back to per-round dicts (legacy standalone use).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
 from repro.core import invariant as inv
+from repro.core import spans
 from repro.core import straggler as strag
 from repro.core.dropout import get_policy, keep_count
 
@@ -54,8 +54,12 @@ class RoundLog:
     rates: Dict[int, float] = field(default_factory=dict)
     threshold: float = 0.0
     invariant_frac: float = 0.0
-    calib_time: float = 0.0                # server-side overhead (real s)
+    # host seconds of calibration (the `fluid.calibrate` span); they
+    # include the host's wait on the cohort program (`fleet.stats_wait`)
+    calib_time: float = 0.0
     accuracy: float = float("nan")
+    spans: Dict[str, float] = field(default_factory=dict)   # core/spans.py
+    counters: Dict[str, int] = field(default_factory=dict)
 
 
 class FluidServer:
@@ -102,11 +106,38 @@ class FluidServer:
         return (self.cfg.fixed_rate if self.cfg.fixed_rate is not None
                 else self.plan.rates[cid])
 
+    def _calibrate_plan(self, calib_ids: List[int], latencies):
+        """Re-detect stragglers and their rates; with a store, write the
+        plan back: stragglers get their rate, everyone else observed
+        returns to the full model."""
+        cfg = self.cfg
+        if self.store is None:
+            self.plan = strag.plan(latencies, frac=cfg.straggler_frac,
+                                   sizes=cfg.submodel_sizes)
+            return
+        self.plan = strag.plan_from_store(
+            self.store, calib_ids, frac=cfg.straggler_frac,
+            sizes=cfg.submodel_sizes)
+        stragglers = set(self.plan.stragglers)
+        self.store = self.store.assign_rates(
+            np.asarray(calib_ids, np.int32),
+            np.asarray([self._rate_for(c) if c in stragglers else 1.0
+                        for c in calib_ids], np.float32))
+
     # ------------------------------------------------------------------ round
     def run_round(self, eval_now: bool = False, backend=None) -> RoundLog:
         """One synchronous FLuID round via `backend` (default: the one from
         __init__ — the population driver passes a fresh cohort backend
-        per round). Store slots are client ids."""
+        per round). Store slots are client ids. The round's host spans and
+        counters (core/spans.py) land in the log's `spans` and `counters`;
+        `calib_time` is the `fluid.calibrate` span."""
+        with spans.record() as rec:
+            log = self._run_round(eval_now, backend)
+        log.spans, log.counters = rec.spans, rec.counters
+        log.calib_time = log.spans.get("fluid.calibrate", 0.0)
+        return log
+
+    def _run_round(self, eval_now: bool, backend) -> RoundLog:
         cfg = self.cfg
         backend = self.backend if backend is None else backend
         if backend is None:
@@ -184,44 +215,32 @@ class FluidServer:
         # -------- aggregate
         self.params = result.aggregate(self.params)
 
-        # -------- calibration (server-side; wall-clock measured as overhead)
-        t0 = time.perf_counter()
-        # calibration scope: the clients with fresh observations — the
-        # cohort for synchronous backends, this buffer's arrivals for async
-        calib_ids = list(getattr(result, "calib_ids", None) or ids)
-        if self.round % cfg.calibrate_every == 0:
-            per_client = result.non_straggler_stats(prev)
-            if per_client:
-                if self.th is None:
-                    self.th = inv.initial_threshold(per_client)
-                if self.store is not None:
-                    self.plan = strag.plan_from_store(
-                        self.store, calib_ids, frac=cfg.straggler_frac,
-                        sizes=cfg.submodel_sizes)
-                else:
-                    self.plan = strag.plan(latencies,
-                                           frac=cfg.straggler_frac,
-                                           sizes=cfg.submodel_sizes)
-                target = self._drop_target(
-                    {c: cfg.fixed_rate for c in self.plan.stragglers}
-                    if cfg.fixed_rate is not None else self.plan.rates)
-                if target:
-                    self.th = inv.calibrate_threshold(per_client, target,
-                                                      self.th)
-                self.policy.observe(per_client, self.th)
-                log.threshold = float(self.th)
-                log.invariant_frac = (inv.count_invariant(per_client, self.th)
-                                      / self._total_neurons())
-                if self.store is not None:
-                    # write the new plan back: stragglers get their rate,
-                    # everyone else observed returns to the full model
-                    stragglers = set(self.plan.stragglers)
-                    self.store = self.store.assign_rates(
-                        np.asarray(calib_ids, np.int32),
-                        np.asarray([self._rate_for(c) if c in stragglers
-                                    else 1.0 for c in calib_ids],
-                                   np.float32))
-        log.calib_time = time.perf_counter() - t0
+        # -------- calibration (server-side, on the host)
+        with spans.span("fluid.calibrate"):
+            # calibration scope: the clients with fresh observations — the
+            # cohort for synchronous backends, this buffer's arrivals for
+            # async
+            calib_ids = list(getattr(result, "calib_ids", None) or ids)
+            if self.round % cfg.calibrate_every == 0:
+                per_client = result.non_straggler_stats(prev)
+                if per_client:
+                    with spans.span("fluid.plan"):
+                        self._calibrate_plan(calib_ids, latencies)
+                        target = self._drop_target(
+                            {c: cfg.fixed_rate for c in self.plan.stragglers}
+                            if cfg.fixed_rate is not None
+                            else self.plan.rates)
+                    with spans.span("fluid.threshold"):
+                        if self.th is None:
+                            self.th = inv.initial_threshold(per_client)
+                        if target:
+                            self.th = inv.calibrate_threshold(
+                                per_client, target, self.th)
+                        self.policy.observe(per_client, self.th)
+                        log.threshold = float(self.th)
+                        log.invariant_frac = (
+                            inv.count_invariant(per_client, self.th)
+                            / self._total_neurons())
 
         if eval_now and self.eval_fn is not None:
             log.accuracy = float(self.eval_fn(self.params))

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
+
 EPS = 1e-8
 TH_GROWTH = 1.25
 
@@ -124,6 +126,7 @@ def calibrate_threshold(per_client_stats, n_drop_target: int, th0: float,
     """Increment th until #invariant >= n_drop_target (Algorithm 1 l.22)."""
     th = max(float(th0), EPS)
     for _ in range(max_iters):
+        spans.count("fluid.threshold_iters")
         if count_invariant(per_client_stats, th) >= n_drop_target:
             return th
         th *= TH_GROWTH

@@ -189,7 +189,7 @@ class AsyncRoundResult:
                 row = int(a.result.mask_idx[a.slot])
                 mask = jax.tree.map(lambda b: b[row], a.result.mask_bank)
             out.append(ClientUpdate(delta, int(a.result.weights[a.slot]),
-                                    mask, a.latency, 0.0, a.cid))
+                                    mask, a.latency, a.cid))
         return out
 
 

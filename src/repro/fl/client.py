@@ -150,8 +150,6 @@ class SimClient:
 
     # ------------------------------------------------------------------ train
     def train(self, params, keep_map=None, rate: float = 1.0) -> ClientUpdate:
-        import time
-        t0 = time.perf_counter()
         run = _train_fn(self.model_cls)
         bs = self.eff_batch_size
         nb = self.n_samples // bs
@@ -162,10 +160,9 @@ class SimClient:
             ys = jnp.asarray(self.y[order].reshape(nb, bs))
             new_params = run(new_params, xs, ys, self.lr)
         delta = jax.tree.map(lambda a, b: a - b, new_params, params)
-        real = time.perf_counter() - t0
         n_par = sum(x.size for x in jax.tree.leaves(params))
         sim = self._sim_time(rate, n_par)
-        return ClientUpdate(delta, self.n_samples, None, sim, real, self.id)
+        return ClientUpdate(delta, self.n_samples, None, sim, self.id)
 
     def evaluate(self, params, x=None, y=None):
         x = self.x if x is None else x

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import invariant as inv
+from repro.core import spans
 from repro.core import submodel as sub
 from repro.core.aggregate import ClientUpdate, aggregate_stacked
 from repro.core.maskbank import MaskBank
@@ -159,10 +160,16 @@ class CohortResult:
                         and self._is_member(i)], dtype=np.int32)
         if sel.size == 0:
             return []
-        picked = jax.tree.map(lambda d: d[sel], self.deltas)
-        stacked = self.engine._stats_fn(prev_params, picked)
-        return [{g: np.asarray(v[i]) for g, v in stacked.items()}
-                for i in range(sel.size)]
+        with spans.span("fleet.stats_wait"):
+            picked = jax.tree.map(lambda d: d[sel], self.deltas)
+            stacked = self.engine._stats_fn(prev_params, picked)
+            # the copies below wait on this anyway (and with it on the
+            # cohort program): the wait is timed here, not there
+            jax.block_until_ready(stacked)
+        spans.count("fleet.stats_copies", sel.size * len(stacked))
+        with spans.span("fleet.stats_copy"):
+            return [{g: np.asarray(v[i]) for g, v in stacked.items()}
+                    for i in range(sel.size)]
 
     def updates(self) -> List[ClientUpdate]:
         """Materialize sequential-style ClientUpdates (tests / inspection)."""
@@ -176,7 +183,7 @@ class CohortResult:
                 row = int(self.mask_idx[i])
                 mask = jax.tree.map(lambda b: b[row], self.mask_bank)
             out.append(ClientUpdate(delta, int(self.weights[i]), mask,
-                                    self.sim_times[cid], 0.0, cid))
+                                    self.sim_times[cid], cid))
         return out
 
 
@@ -232,25 +239,26 @@ class FleetEngine:
         number of *real* SGD steps per client by zero-weighting the tail —
         step counts are data riding on the same padding as ragged shards.
 
-        Rebuilt host-side every round (only the permutations change); at
-        paper scales this is <2% of the cohort program's runtime. If fleets
-        outgrow that, stage shards on device once and gather by permutation
-        indices instead."""
-        C = len(self.clients)
-        feat = self.clients[0].x.shape[1:]
-        xs = np.zeros((C, self.steps, self.bs, *feat),
-                      self.clients[0].x.dtype)
-        ys = np.zeros((C, self.steps, self.bs), np.int32)
-        sw = np.zeros((C, self.steps, self.bs), np.float32)
-        for i, c in enumerate(self.clients):
-            x, y = c.local_batches()
-            s, b = x.shape[0], x.shape[1]
-            xs[i, :s, :b] = x
-            ys[i, :s, :b] = y
-            sw[i, :s, :b] = 1.0
-            if n_steps is not None:
-                sw[i, int(n_steps[i]):] = 0.0
-        return jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(sw)
+        Rebuilt host-side every round (only the permutations change) and
+        copied to the device whole; the `fleet.stage` span (benchmark
+        metric `stage_ms`) times both. Staging shards on the device once
+        and gathering by permutation indices would remove it."""
+        with spans.span("fleet.stage"):
+            C = len(self.clients)
+            feat = self.clients[0].x.shape[1:]
+            xs = np.zeros((C, self.steps, self.bs, *feat),
+                          self.clients[0].x.dtype)
+            ys = np.zeros((C, self.steps, self.bs), np.int32)
+            sw = np.zeros((C, self.steps, self.bs), np.float32)
+            for i, c in enumerate(self.clients):
+                x, y = c.local_batches()
+                s, b = x.shape[0], x.shape[1]
+                xs[i, :s, :b] = x
+                ys[i, :s, :b] = y
+                sw[i, :s, :b] = 1.0
+                if n_steps is not None:
+                    sw[i, int(n_steps[i]):] = 0.0
+            return jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(sw)
 
     def _mask_bank(self, params, keep_maps: Dict[int, dict]):
         """(bank, idx, n_params_by_row): all-ones row 0 + one row per

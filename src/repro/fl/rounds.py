@@ -138,7 +138,7 @@ class SequentialBackend:
                 full_delta, mask = sub.embed_delta(
                     u.delta, params, self.unit_specs, keep)
                 u = ClientUpdate(full_delta, u.n_samples, mask,
-                                 u.sim_time, u.real_time, c.id)
+                                 u.sim_time, c.id)
             else:
                 u = c.train(params)
             updates.append(u)
