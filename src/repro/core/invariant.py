@@ -14,12 +14,14 @@ uses their updates for this). The initial threshold is the client-average of
 the per-client minimum neuron stat; it is then incremented geometrically
 until at least the target number of neurons is invariant (Algorithm 1,
 lines 9 / 22).
+
+The statistics are computed on the device; the votes and the threshold
+search run on the host, in NumPy, over one (N, size) array per unit group.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence
+from typing import Dict, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -84,50 +86,67 @@ def neuron_stats(prev_tree, new_tree, unit_specs,
             for g in unit_specs}
 
 
-def initial_threshold(per_client_stats: Sequence[Dict[str, jnp.ndarray]]):
+def _stack(per_client_stats) -> Dict[str, np.ndarray]:
+    """Per group: the clients' stats as one (N, size) float32 host array.
+    Rows may be NumPy arrays or jax arrays (the sequential backend's)."""
+    return {g: np.stack([np.asarray(cs[g]) for cs in per_client_stats])
+            .astype(np.float32, copy=False)
+            for g in per_client_stats[0]}
+
+
+def _votes(stacked: Dict[str, np.ndarray], th: float) -> Dict[str, np.ndarray]:
+    # th compares as float32, as a weakly typed Python float meets a
+    # float32 array, so the votes do not move with th's float64 digits
+    th32 = np.float32(th)
+    return {g: (a <= th32).sum(axis=0, dtype=np.int32)
+            for g, a in stacked.items()}
+
+
+def _majority(stacked: Dict[str, np.ndarray],
+              th: float) -> Dict[str, np.ndarray]:
+    n = len(next(iter(stacked.values())))
+    return {g: c > n / 2 for g, c in _votes(stacked, th).items()}
+
+
+def _n_invariant(stacked: Dict[str, np.ndarray], th: float) -> int:
+    return int(sum(m.sum() for m in _majority(stacked, th).values()))
+
+
+def initial_threshold(per_client_stats: Sequence[Dict[str, np.ndarray]]):
     """Average over clients of the min percent-update over all neurons."""
-    mins = []
-    for cs in per_client_stats:
-        allv = jnp.concatenate([v.ravel() for v in cs.values()])
-        mins.append(allv.min())
-    return float(jnp.mean(jnp.stack(mins)))
+    mins = np.min([a.min(axis=1) for a in _stack(per_client_stats).values()],
+                  axis=0)
+    return float(mins.mean(dtype=np.float64).astype(np.float32))
 
 
-def invariant_counts(per_client_stats: Sequence[Dict[str, jnp.ndarray]],
+def invariant_counts(per_client_stats: Sequence[Dict[str, np.ndarray]],
                      th: float) -> Dict[str, np.ndarray]:
     """Per group: #clients for which each neuron is below th."""
-    out = {}
-    for g in per_client_stats[0]:
-        votes = jnp.stack([cs[g] <= th for cs in per_client_stats])
-        out[g] = np.asarray(votes.sum(axis=0))
-    return out
+    return _votes(_stack(per_client_stats), th)
 
 
 def mean_stats(per_client_stats) -> Dict[str, np.ndarray]:
-    return {g: np.asarray(jnp.stack([cs[g] for cs in per_client_stats])
-                          .mean(axis=0))
-            for g in per_client_stats[0]}
+    return {g: a.mean(axis=0, dtype=np.float64).astype(np.float32)
+            for g, a in _stack(per_client_stats).items()}
 
 
 def invariant_mask(per_client_stats, th: float) -> Dict[str, np.ndarray]:
     """Neurons invariant for the strict majority of clients."""
-    n = len(per_client_stats)
-    counts = invariant_counts(per_client_stats, th)
-    return {g: c > n / 2 for g, c in counts.items()}
+    return _majority(_stack(per_client_stats), th)
 
 
 def count_invariant(per_client_stats, th: float) -> int:
-    m = invariant_mask(per_client_stats, th)
-    return int(sum(v.sum() for v in m.values()))
+    return _n_invariant(_stack(per_client_stats), th)
 
 
 def calibrate_threshold(per_client_stats, n_drop_target: int, th0: float,
                         max_iters: int = 200) -> float:
     """Increment th until #invariant >= n_drop_target (Algorithm 1 l.22)."""
+    stacked = _stack(per_client_stats)
     th = max(float(th0), EPS)
     for _ in range(max_iters):
         spans.count("fluid.threshold_iters")
-        if count_invariant(per_client_stats, th) >= n_drop_target:
+        if _n_invariant(stacked, th) >= n_drop_target:
             return th
         th *= TH_GROWTH
     return th
@@ -138,12 +157,12 @@ def calibrate_threshold_per_group(per_client_stats, drop_targets: Dict[str, int]
                                   ) -> Dict[str, float]:
     """Per-layer thresholds (paper: 'FLuID can have a different drop
     threshold for each layer')."""
+    stacked = _stack(per_client_stats)
     out = {}
     for g, target in drop_targets.items():
         th = max(float(th0), EPS)
-        stats_g = [{g: cs[g]} for cs in per_client_stats]
         for _ in range(max_iters):
-            if count_invariant(stats_g, th) >= target:
+            if _n_invariant({g: stacked[g]}, th) >= target:
                 break
             th *= TH_GROWTH
         out[g] = th

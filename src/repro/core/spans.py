@@ -16,12 +16,15 @@ The names, what each covers, and the benchmark metric that reads it
     fluid.calibrate        FluidServer's calibration block      calib_ms
     fleet.stats_wait       gather + stats dispatch, and the
                            host's wait on the cohort program    stats_wait_ms
-    fleet.stats_copy       per-client device-to-host copies     stats_copy_ms
+    fleet.stats_copy       device-to-host copies of the
+                           stacked stats, one per unit group    stats_copy_ms
     fluid.threshold        threshold search, policy.observe     threshold_ms
     fluid.plan             straggler plan, store rates          plan_ms
-    fluid.threshold_iters  count_invariant passes of
+    fluid.threshold_iters  invariant-count passes of
                            calibrate_threshold (counter)        threshold_iters
-    fleet.stats_copies     per-client stat copies (counter)     stats_copies
+    fleet.stats_copies     device-to-host copies of the
+                           stacked stats, one per unit group
+                           (counter)                            stats_copies
 
 One thread records at a time.
 """

@@ -154,7 +154,8 @@ class CohortResult:
                                  self.mask_bank, self.mask_idx)
 
     def non_straggler_stats(self, prev_params) -> List[Dict[str, np.ndarray]]:
-        """Per-client invariant-neuron stats, computed batched on device."""
+        """Per-client invariant-neuron stats, computed batched on device
+        and copied to the host one (N, size) array per unit group."""
         sel = np.array([i for i, cid in enumerate(self.client_ids)
                         if cid not in self.straggler_ids
                         and self._is_member(i)], dtype=np.int32)
@@ -163,13 +164,15 @@ class CohortResult:
         with spans.span("fleet.stats_wait"):
             picked = jax.tree.map(lambda d: d[sel], self.deltas)
             stacked = self.engine._stats_fn(prev_params, picked)
-            # the copies below wait on this anyway (and with it on the
+            # the copy below waits on this anyway (and with it on the
             # cohort program): the wait is timed here, not there
             jax.block_until_ready(stacked)
-        spans.count("fleet.stats_copies", sel.size * len(stacked))
+        # one device-to-host copy per unit group; each client's row is a
+        # view into the group's (N, size) host array
+        spans.count("fleet.stats_copies", len(stacked))
         with spans.span("fleet.stats_copy"):
-            return [{g: np.asarray(v[i]) for g, v in stacked.items()}
-                    for i in range(sel.size)]
+            host = jax.device_get(stacked)
+        return [{g: v[i] for g, v in host.items()} for i in range(sel.size)]
 
     def updates(self) -> List[ClientUpdate]:
         """Materialize sequential-style ClientUpdates (tests / inspection)."""

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import spans
 from repro.core import submodel as sub
 from repro.core.aggregate import ClientUpdate, aggregate, aggregate_stacked
 from repro.core.dropout import DropoutPolicy
@@ -256,3 +257,31 @@ def test_ragged_shards_match_sequential(fleet_sim):
         ref = c.train(params)
         assert u.sim_time == pytest.approx(ref.sim_time, rel=1e-12)
         _tree_close(u.delta, ref.delta, atol=2e-5)
+
+
+def test_non_straggler_stats_rows_match_per_client_copies(fleet_sim):
+    """The stacked stats come to the host one copy per unit group, and the
+    rows equal a per-client copy of each client's slice, in cohort order,
+    without the straggler or the padding slot."""
+    engine = fleet_sim.server.engine
+    params = fleet_sim.server.params
+    keep = DropoutPolicy("ordered", engine.unit_specs, seed=0).keep_map(0.5)
+    ids = [c.id for c in engine.clients]
+    members = np.array([True, True, False, True])
+    cohort = engine.run_cohort(params, {ids[1]: keep}, {ids[1]: 0.5},
+                               members=members)
+    with spans.record() as rec:
+        rows = cohort.non_straggler_stats(params)
+    assert rec.counters["fleet.stats_copies"] == len(engine.unit_specs)
+
+    sel = [i for i, cid in enumerate(ids) if cid != ids[1] and members[i]]
+    picked = jax.tree.map(lambda d: d[np.asarray(sel)], cohort.deltas)
+    stacked = engine._stats_fn(params, picked)
+    want = [{g: np.asarray(v[i]) for g, v in stacked.items()}
+            for i in range(len(sel))]
+    assert len(rows) == len(want) == 2
+    for got_row, want_row in zip(rows, want):
+        assert list(got_row) == list(want_row)
+        for g in want_row:
+            assert got_row[g].dtype == np.float32
+            np.testing.assert_array_equal(got_row[g], want_row[g])

@@ -94,10 +94,13 @@ def test_round_log_holds_the_spans(rounds):
 
 
 def test_stats_copies_counter(rounds):
+    """One device-to-host copy per unit group, whatever the number of
+    non-straggler clients."""
     log = rounds["logs"][1]
     n_non = len(rounds["sim"].clients) - rounds["n_sub"]
     groups = len(rounds["sim"].model_cls.UNIT_SPECS)
-    assert log.counters["fleet.stats_copies"] == n_non * groups
+    assert n_non > 1
+    assert log.counters["fleet.stats_copies"] == groups
 
 
 def test_threshold_iters_counter(rounds):
