@@ -16,6 +16,9 @@ The names, what each covers, and the benchmark metric that reads it
     fluid.calibrate        FluidServer's calibration block      calib_ms
     fleet.stats_wait       gather + stats dispatch, and the
                            host's wait on the cohort program    stats_wait_ms
+    fleet.cohort_wait      inside fleet.stats_wait: the host's
+                           wait on the cohort program's deltas,
+                           before the gather is dispatched      cohort_wait_ms
     fleet.stats_copy       device-to-host copies of the
                            stacked stats, one per unit group    stats_copy_ms
     fluid.threshold        threshold search, policy.observe     threshold_ms

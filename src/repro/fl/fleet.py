@@ -14,10 +14,12 @@ This engine runs the *entire cohort* as one compiled program:
     consumer weight of a dropped neuron is zeroed, so full-model clients and
     every dropout rate share ONE compiled shape; the mask is data, not
     shape.
-  * Local SGD for all C clients is jax.vmap over a jax.lax.scan of
-    minibatches. Shards of different sizes pad to the cohort-max step count
-    and batch size; padding is neutralized by per-sample loss weights.
-  * Hyperparameters are data too: learning rates are a vmapped (C,) array
+  * Local SGD for all C clients is one jax.lax.map over the clients of a
+    jax.lax.scan of minibatches: each client's steps run at its own batch
+    shape inside the one program (see _cohort_fn for why not jax.vmap).
+    Shards of different sizes pad to the cohort-max step count and batch
+    size; padding is neutralized by per-sample loss weights.
+  * Hyperparameters are data too: learning rates are a mapped (C,) array
     and per-client step counts ride on the same zero-weight padding that
     absorbs ragged shards, so heterogeneous (lr, local-epochs) cohorts —
     and the serving engine's per-request sub-models (launch/serving.py) —
@@ -59,13 +61,11 @@ from repro.kernels import ops as kops
 
 _COHORT_CACHE: Dict[tuple, callable] = {}
 
-# lax.scan under vmap is pathological on CPU for batched-weight train steps
-# (measured ~6x slower than the identical unrolled program: the loop body
-# blocks cross-step fusion and re-materializes the (C, ...) carry). Small
-# step counts unroll fully in Python; longer ones use scan's unroll knob.
-# The knob is a CPU workaround: on a TPU it only multiplies compile time
-# (the FEMNIST CNN cohort program at C=32 compiles for a v5e in half the
-# time at unroll 1), so other backends keep the plain loop.
+# Small step counts unroll fully in Python; longer ones use scan's unroll
+# knob on the CPU (a speed-up for the CPU tests' cohort programs). On a
+# TPU the knob only multiplies compile time (the FEMNIST CNN cohort program
+# at C=32 compiles for a v5e in half the time at unroll 1), so other
+# backends keep the plain loop.
 _FULL_UNROLL_STEPS = 16
 _SCAN_UNROLL_CPU = 8
 
@@ -75,7 +75,18 @@ def _scan_unroll() -> int:
 
 
 def _cohort_fn(model_cls, use_kernels: bool = False):
-    """One compiled program: vmapped masked local SGD for a whole cohort.
+    """One compiled program: masked local SGD for a whole cohort.
+
+    The clients are mapped with jax.lax.map, one client's scan at a time,
+    and not batched with jax.vmap. Under vmap every convolution with
+    per-client weights becomes a grouped convolution over the cohort, and
+    on a TPU v5e at default precision some of those come out wrong: at 64
+    clients the VGG-9's c1a, c3a and c3b weight gradients differ from the
+    exact ("highest") gradient by 62-151% of its norm, against 13-22% for
+    the same convolutions run per client (one bfloat16 pass through nine
+    layers). Per-client convolutions are also the faster form there (a
+    VGG-9 gradient step for 64 clients: 15.1 ms mapped, 26.3 ms vmapped;
+    FEMNIST CNN at 128: 9.8 ms and 10.9 ms).
 
     use_kernels routes every forward/backward through the model's
     `apply_kernels` Pallas path (models/kernel_models.py): dropped
@@ -124,7 +135,8 @@ def _cohort_fn(model_cls, use_kernels: bool = False):
                                         unroll=_scan_unroll())
                 # every update step carried the mask factor => pre-zeroed
                 return jax.tree.map(lambda a, b: a - b, w, w0)
-            return jax.vmap(one_client)(mask_idx, xs, ys, sw, lrs)
+            return jax.lax.map(lambda a: one_client(*a),
+                               (mask_idx, xs, ys, sw, lrs))
         _COHORT_CACHE[key] = run
     return _COHORT_CACHE[key]
 
@@ -162,6 +174,10 @@ class CohortResult:
         if sel.size == 0:
             return []
         with spans.span("fleet.stats_wait"):
+            # the host's wait on local SGD alone, before the gather and
+            # the stats program are queued behind it
+            with spans.span("fleet.cohort_wait"):
+                jax.block_until_ready(self.deltas)
             picked = jax.tree.map(lambda d: d[sel], self.deltas)
             stacked = self.engine._stats_fn(prev_params, picked)
             # the copy below waits on this anyway (and with it on the
@@ -191,11 +207,11 @@ class CohortResult:
 
 
 class FleetEngine:
-    """Runs a homogeneous-model client fleet as single vmapped programs.
+    """Runs a homogeneous-model client fleet as single compiled programs.
 
     The model architecture is uniform across the cohort (one param tree
     shape); per-client hyperparameters (lr, local epochs / step counts) and
-    per-client sub-model masks are vmapped data, not program structure.
+    per-client sub-model masks are mapped data, not program structure.
     """
 
     def __init__(self, model_cls, clients: Sequence[FleetClient], unit_specs,
@@ -315,7 +331,7 @@ class FleetEngine:
 
         lr: optional scalar or (C,) array overriding the clients' own
         learning rates; n_steps: optional (C,) int array capping each
-        client's real SGD steps. Both are vmapped data — heterogeneous
+        client's real SGD steps. Both are mapped data — heterogeneous
         values reuse the same compiled program as the uniform cohort.
 
         members: optional (C,) bool marking which slots are real clients —

@@ -1,6 +1,6 @@
 """Vectorized fleet engine == sequential reference path (fl/fleet.py).
 
-The acceptance contract: with the same seeds, one vmapped cohort round
+The acceptance contract: with the same seeds, one compiled cohort round
 reproduces the per-client sequential round — deltas (full-model AND
 masked-straggler clients), emulated times, and the aggregated params — up
 to float summation order; and the fused device-side aggregation matches
@@ -16,7 +16,7 @@ from repro.core import submodel as sub
 from repro.core.aggregate import ClientUpdate, aggregate, aggregate_stacked
 from repro.core.dropout import DropoutPolicy
 from repro.fl.client import FleetClient, SimClient
-from repro.fl.fleet import FleetEngine
+from repro.fl.fleet import FleetEngine, _cohort_fn
 from repro.fl.simulation import (CohortConfig, SimulationConfig,
                                  build_simulation)
 
@@ -285,3 +285,29 @@ def test_non_straggler_stats_rows_match_per_client_copies(fleet_sim):
         for g in want_row:
             assert got_row[g].dtype == np.float32
             np.testing.assert_array_equal(got_row[g], want_row[g])
+
+
+@pytest.mark.parametrize("model", ["femnist_cnn", "cifar_vgg9"])
+def test_cohort_program_has_no_grouped_convolutions(model):
+    """Each client's convolutions run at the client's own shapes. Batched
+    over the cohort (jax.vmap), per-client weights turn every convolution
+    into one grouped over the clients, and a TPU v5e at default precision
+    computes some of those wrong (the VGG-9's c1a, c3a and c3b weight
+    gradients at 64 clients). The lowered program is checked here on the
+    CPU; the wrong numbers themselves show only on the chip."""
+    import re
+    from repro.models.small import MODELS
+    model_cls = MODELS[model]
+    c, s, b = 3, 2, 2
+    sd = jax.ShapeDtypeStruct
+    params = jax.eval_shape(model_cls.init, jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda a: sd(a.shape, jnp.float32), params)
+    bank = jax.tree.map(lambda a: sd((2,) + a.shape, jnp.float32), params)
+    text = _cohort_fn(model_cls).lower(
+        p, bank, sd((c,), jnp.int32),
+        sd((c, s, b) + model_cls.input_shape, jnp.float32),
+        sd((c, s, b), jnp.int32), sd((c, s, b), jnp.float32),
+        sd((c,), jnp.float32), n_steps=s).as_text()
+    assert "stablehlo.convolution" in text
+    groups = re.findall(r"(?:feature|batch)_group_count = (\d+)", text)
+    assert groups and set(groups) == {"1"}, sorted(set(groups))

@@ -93,6 +93,31 @@ def test_round_log_holds_the_spans(rounds):
     assert spans.last_rounds(sim.server.round)[-1].spans == log.spans
 
 
+def test_cohort_wait_nested_in_stats_wait(rounds, tmp_path):
+    """The wait on the cohort program has its own span inside
+    `fleet.stats_wait`: in the round log it is no longer than its parent,
+    and on the profiler's host plane it lies within the parent's extent."""
+    for log in rounds["logs"]:
+        assert 0.0 < log.spans["fleet.cohort_wait"] \
+            <= log.spans["fleet.stats_wait"]
+    with jax.profiler.trace(str(tmp_path)):
+        rounds["sim"].server.run_round()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    ext = {}
+    for plane in data.planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in ("fleet.stats_wait", "fleet.cohort_wait"):
+                    ext.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    (p0, p1), = ext["fleet.stats_wait"]
+    (c0, c1), = ext["fleet.cohort_wait"]
+    assert p0 <= c0 <= c1 <= p1
+
+
 def test_stats_copies_counter(rounds):
     """One device-to-host copy per unit group, whatever the number of
     non-straggler clients."""
