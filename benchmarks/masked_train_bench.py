@@ -69,16 +69,19 @@ def _keep_maps(engine, rate):
 
 def _time_cohort(engine, params, keep_maps, iters=3):
     """Steady-state seconds per cohort train step (the compiled program
-    only — host-side shard staging and mask-bank dedupe are excluded)."""
+    only — host-side staging, the one upload of the clients' samples and
+    mask-bank dedupe are excluded)."""
     import jax
     import jax.numpy as jnp
 
-    xs, ys, sw = engine._stacked_data()
+    order, ys, sw = engine._stacked_data()
+    client_x = engine._client_data()
     bank, idx, _ = engine._mask_bank(params, keep_maps)
     lrs = jnp.asarray(engine.lrs)
 
     def once():
-        out = engine._run(params, bank, idx, xs, ys, sw, lrs, engine.steps)
+        out = engine._run(params, bank, idx, client_x, order, ys, sw, lrs,
+                          engine.steps)
         jax.tree.leaves(out)[0].block_until_ready()
         return out
     once()                                        # compile + warm caches

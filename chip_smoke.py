@@ -208,7 +208,8 @@ def phase_kernels(workload="femnist_kernel", n_clients=32, per_client=200,
     sds = jax.ShapeDtypeStruct
     lowered = eng._run.lower(
         params, res.mask_bank, res.mask_idx,
-        sds((C, eng.steps, eng.bs) + x0.shape[1:], x0.dtype),
+        sds((C, eng.rows) + x0.shape[1:], x0.dtype),
+        sds((C, eng.steps, eng.bs), np.int32),
         sds((C, eng.steps, eng.bs), np.int32),
         sds((C, eng.steps, eng.bs), np.float32),
         sds((C,), np.float32), n_steps=eng.steps).as_text()

@@ -375,7 +375,8 @@ def check_population_no_host_sync() -> List[Violation]:
     bank = jax.tree.map(lambda p: p[None].astype(jnp.float32) * 0 + 1,
                         params)
     S, Cs, steps, bs = 2, 2, 1, 20
-    xs = jnp.zeros((S, Cs, steps, bs, 32), jnp.float32)
+    cx = jnp.zeros((S, Cs, 2 * bs, 32), jnp.float32)
+    order = jnp.zeros((S, Cs, steps, bs), jnp.int32)
     ys = jnp.zeros((S, Cs, steps, bs), jnp.int32)
     sw = jnp.ones((S, Cs, steps, bs), jnp.float32)
     mi = jnp.zeros((S, Cs), jnp.int32)
@@ -384,7 +385,7 @@ def check_population_no_host_sync() -> List[Violation]:
     out += _trace_violations(
         "population-no-host-sync", "sharded_cohort_program",
         functools.partial(run, n_steps=steps),
-        params, bank, mi, xs, ys, sw, lrs, w)
+        params, bank, mi, cx, order, ys, sw, lrs, w)
     num = jax.tree.map(jnp.zeros_like, params)
     out += _trace_violations(
         "population-no-host-sync", "combine_partials",

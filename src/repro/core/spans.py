@@ -11,8 +11,13 @@ round (`record()`) and copies it into its `RoundLog`; outside a record,
 The names, what each covers, and the benchmark metric that reads it
 (bench/metrics/<metric>.py):
 
-    fleet.stage            FleetEngine._stacked_data, with the
-                           host-to-device copies                stage_ms
+    fleet.stage            FleetEngine._stacked_data: the
+                           round's sample orders, labels and
+                           weights, with their host-to-device
+                           copies                               stage_ms
+    fleet.data_upload      FleetEngine._client_data: the one
+                           upload of an engine's client
+                           samples                              -
     fluid.calibrate        FluidServer's calibration block      calib_ms
     fleet.stats_wait       gather + stats dispatch, and the
                            host's wait on the cohort program    stats_wait_ms
@@ -28,6 +33,9 @@ The names, what each covers, and the benchmark metric that reads it
     fleet.stats_copies     device-to-host copies of the
                            stacked stats, one per unit group
                            (counter)                            stats_copies
+    fleet.data_uploads     uploads of an engine's client
+                           samples: 1 in its first round, then
+                           none (counter)                       -
 
 One thread records at a time.
 """

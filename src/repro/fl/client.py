@@ -10,8 +10,8 @@ speed can be changed mid-run to emulate runtime variation (paper Fig. 4b).
 Two execution paths share the same data/speed model:
   * SimClient.train — the sequential reference: one jit call per client,
     stragglers get a physically extracted sub-model (core/submodel.extract).
-  * FleetClient — the batched path: exposes the epoch batch order and the
-    time model so fl/fleet.py can run a whole cohort as one vmapped
+  * FleetClient — the batched path: exposes the epoch sample order and
+    the time model so fl/fleet.py can run a whole cohort as one compiled
     program. Both consume the per-client RNG in the same order
     (local_epochs permutations, then one noise draw), so a fleet round is
     bit-identical to the sequential round in everything but float summation
@@ -174,20 +174,20 @@ class SimClient:
 @dataclass
 class FleetClient(SimClient):
     """Batched-path client: same shard, speed model, and RNG stream as
-    SimClient, but training happens inside fl/fleet.py's single vmapped
-    cohort program instead of a per-client `train` call."""
+    SimClient, but training happens inside fl/fleet.py's cohort program,
+    which maps the clients one at a time, instead of a per-client `train`
+    call."""
 
-    def local_batches(self):
-        """(xs, ys) for one round: (local_epochs * nb, bs, ...) numpy arrays,
-        consuming the RNG exactly like sequential train()."""
+    def local_orders(self) -> np.ndarray:
+        """The round's (local_epochs * nb, bs) int32 sample order into this
+        client's shard, batch by batch, consuming the RNG exactly like
+        sequential train(): one epoch permutation per local epoch, in
+        order."""
         bs = self.eff_batch_size
         nb = self.n_samples // bs
         orders = np.concatenate([self._epoch_order()
                                  for _ in range(self.local_epochs)])
-        xs = self.x[orders].reshape(self.local_epochs * nb, bs,
-                                    *self.x.shape[1:])
-        ys = self.y[orders].reshape(self.local_epochs * nb, bs)
-        return xs, ys
+        return orders.reshape(self.local_epochs * nb, bs).astype(np.int32)
 
     def draw_sim_time(self, rate: float, n_params: int) -> float:
         """The post-training noise draw, in SimClient.train's RNG order."""

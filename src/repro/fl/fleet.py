@@ -19,6 +19,10 @@ This engine runs the *entire cohort* as one compiled program:
     shape inside the one program (see _cohort_fn for why not jax.vmap).
     Shards of different sizes pad to the cohort-max step count and batch
     size; padding is neutralized by per-sample loss weights.
+  * Client samples live on the device: the engine uploads every client's
+    rows once, as one (C, rows, ...) array, and each round sends only the
+    (C, steps, bs) sample order, labels and weights. The program gathers
+    each client's batches from its rows by that order.
   * Hyperparameters are data too: learning rates are a mapped (C,) array
     and per-client step counts ride on the same zero-weight padding that
     absorbs ragged shards, so heterogeneous (lr, local-epochs) cohorts —
@@ -103,15 +107,21 @@ def _cohort_fn(model_cls, use_kernels: bool = False):
         loss = make_weighted_loss(model_cls)
 
         @functools.partial(jax.jit, static_argnames=("n_steps",))
-        def run(params, mask_bank, mask_idx, xs, ys, sw, lrs, n_steps):
+        def run(params, mask_bank, mask_idx, client_x, order, ys, sw, lrs,
+                n_steps):
             """params: full tree (broadcast); mask_bank: (K, ...) leaves;
-            mask_idx: (C,); xs: (C, S, bs, ...); ys: (C, S, bs);
-            sw: (C, S, bs) per-sample weights — 1.0 on real samples, 0.0 on
-            batch/step padding (an all-zero step is a no-op);
-            lrs: (C,) per-client learning rates (hyperparameters are data —
-            heterogeneous cohorts don't re-specialize the program).
-            Returns mask-zeroed full-coordinate deltas, (C, ...) leaves."""
-            def one_client(mi, x, y, v, lr):
+            mask_idx: (C,); client_x: (C, rows, ...) every client's samples;
+            order: (C, S, bs) int32 rows of client_x, batch by batch;
+            ys: (C, S, bs); sw: (C, S, bs) per-sample weights — 1.0 on real
+            samples, 0.0 on batch/step padding (an all-zero step is a
+            no-op); lrs: (C,) per-client learning rates (hyperparameters
+            are data — heterogeneous cohorts don't re-specialize the
+            program). Returns mask-zeroed full-coordinate deltas, (C, ...)
+            leaves."""
+            def one_client(mi, cx, o, y, v, lr):
+                # the client's whole (S, bs, ...) block, gathered once
+                # before its step scan and not step by step
+                x = cx[o]
                 m = jax.tree.map(lambda b: b[mi], mask_bank)
                 w0 = sub.apply_mask(params, m)
                 if use_kernels:
@@ -136,7 +146,7 @@ def _cohort_fn(model_cls, use_kernels: bool = False):
                 # every update step carried the mask factor => pre-zeroed
                 return jax.tree.map(lambda a, b: a - b, w, w0)
             return jax.lax.map(lambda a: one_client(*a),
-                               (mask_idx, xs, ys, sw, lrs))
+                               (mask_idx, client_x, order, ys, sw, lrs))
         _COHORT_CACHE[key] = run
     return _COHORT_CACHE[key]
 
@@ -233,8 +243,14 @@ class FleetEngine:
             [c.local_epochs * (c.n_samples // c.eff_batch_size)
              for c in self.clients], np.int32)
         self.steps = int(self.client_steps.max())
+        # rows of the resident client data: the largest shard, rounded up
+        # past its last full batch, so cohorts whose shards give the same
+        # step count give one shape
+        n_max = max(c.n_samples for c in self.clients)
+        self.rows = (n_max // self.bs + 1) * self.bs
         self.lrs = np.array([c.lr for c in self.clients], np.float32)
         self._run = _cohort_fn(model_cls, self.use_kernels)
+        self._client_x = None          # device (C, rows, ...), on first use
         self._ones_mask: Optional[dict] = None
         self._stats_jit = None
         self._bank_cache = None        # (fingerprint, bank, idx, n_by_row)
@@ -251,33 +267,51 @@ class FleetEngine:
                 lambda p, ds: jax.vmap(lambda d: one(p, d))(ds))
         return self._stats_jit(prev, stacked_deltas)
 
-    def _stacked_data(self, n_steps: Optional[np.ndarray] = None):
-        """(xs, ys, sw): per-client epoch batches padded to (steps, bs);
-        sw is 1.0 on real samples, 0.0 on batch/step padding. Consumes each
-        client's RNG exactly like SimClient.train. n_steps (C,) caps the
-        number of *real* SGD steps per client by zero-weighting the tail —
-        step counts are data riding on the same padding as ragged shards.
+    def _put_client_data(self, x: np.ndarray):
+        return jnp.asarray(x)
 
-        Rebuilt host-side every round (only the permutations change) and
-        copied to the device whole; the `fleet.stage` span (benchmark
-        metric `stage_ms`) times both. Staging shards on the device once
-        and gathering by permutation indices would remove it."""
+    def _client_data(self):
+        """Every client's samples as one device array (C, rows, ...), in the
+        clients' own dtype, rows past a client's shard zero. Uploaded once,
+        on first use, in the `fleet.data_upload` span and counted by the
+        `fleet.data_uploads` counter; the clients' data never changes."""
+        if self._client_x is None:
+            spans.count("fleet.data_uploads")
+            with spans.span("fleet.data_upload"):
+                first = self.clients[0].x
+                x = np.zeros((len(self.clients), self.rows, *first.shape[1:]),
+                             first.dtype)
+                for i, c in enumerate(self.clients):
+                    x[i, :c.n_samples] = c.x
+                self._client_x = self._put_client_data(x)
+        return self._client_x
+
+    def _stacked_data(self, n_steps: Optional[np.ndarray] = None):
+        """(order, ys, sw), each (C, steps, bs): the rows of each client's
+        resident data (`_client_data`) that its epoch batches take, padded
+        to (steps, bs) with row 0; the labels of those samples; and sw, 1.0
+        on real samples, 0.0 on batch/step padding. Consumes each client's
+        RNG exactly like SimClient.train. n_steps (C,) caps the number of
+        *real* SGD steps per client by zero-weighting the tail — step
+        counts are data riding on the same padding as ragged shards.
+
+        Built on the host every round (only the permutations change) and
+        copied to the device, ~12 bytes a sample slot; the `fleet.stage`
+        span (benchmark metric `stage_ms`) times both."""
         with spans.span("fleet.stage"):
             C = len(self.clients)
-            feat = self.clients[0].x.shape[1:]
-            xs = np.zeros((C, self.steps, self.bs, *feat),
-                          self.clients[0].x.dtype)
+            order = np.zeros((C, self.steps, self.bs), np.int32)
             ys = np.zeros((C, self.steps, self.bs), np.int32)
             sw = np.zeros((C, self.steps, self.bs), np.float32)
             for i, c in enumerate(self.clients):
-                x, y = c.local_batches()
-                s, b = x.shape[0], x.shape[1]
-                xs[i, :s, :b] = x
-                ys[i, :s, :b] = y
+                o = c.local_orders()
+                s, b = o.shape
+                order[i, :s, :b] = o
+                ys[i, :s, :b] = c.y[o]
                 sw[i, :s, :b] = 1.0
                 if n_steps is not None:
                     sw[i, int(n_steps[i]):] = 0.0
-            return jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(sw)
+            return jnp.asarray(order), jnp.asarray(ys), jnp.asarray(sw)
 
     def _mask_bank(self, params, keep_maps: Dict[int, dict]):
         """(bank, idx, n_params_by_row): all-ones row 0 + one row per
@@ -311,13 +345,14 @@ class FleetEngine:
         self._bank_cache = (fp, bank, idx, n_by_row)
         return bank, idx, n_by_row
 
-    def _execute(self, params, bank, idx, xs, ys, sw, lrs, weights):
-        """Run the cohort program. Returns (deltas, extra): extra is None
-        here; the sharded subclass (fl/shard_fleet.py) returns the
+    def _execute(self, params, bank, idx, order, ys, sw, lrs, weights):
+        """Run the cohort program on the clients' resident data, in the
+        sample order of `_stacked_data`. Returns (deltas, extra): extra is
+        None here; the sharded subclass (fl/shard_fleet.py) returns the
         hierarchically-reduced aggregation partials instead of recomputing
         them from the gathered deltas."""
-        return self._run(params, bank, idx, xs, ys, sw, lrs,
-                         self.steps), None
+        return self._run(params, bank, idx, self._client_data(), order, ys,
+                         sw, lrs, self.steps), None
 
     def _wrap_result(self, extra, **kw) -> "CohortResult":
         return CohortResult(**kw)
@@ -360,13 +395,13 @@ class FleetEngine:
                                  f"got {members.shape}")
             base_steps = self.client_steps if n_steps is None else n_steps
             n_steps = np.where(members, base_steps, 0).astype(np.int32)
-        xs, ys, sw = self._stacked_data(n_steps)
+        order, ys, sw = self._stacked_data(n_steps)
         bank, idx, n_by_row = self._mask_bank(params, keep_maps)
         w_host = np.asarray([c.n_samples for c in self.clients], np.float32)
         if members is not None:
             w_host = np.where(members, w_host, 0.0).astype(np.float32)
         weights = jnp.asarray(w_host)
-        deltas, extra = self._execute(params, bank, idx, xs, ys, sw,
+        deltas, extra = self._execute(params, bank, idx, order, ys, sw,
                                       jnp.asarray(lrs), weights)
         idx_host = np.asarray(idx)
         sim_times = {

@@ -15,7 +15,7 @@ behind one small protocol:
 
 SequentialBackend is the numerical reference (one jit call per client,
 physically extracted sub-models); FleetBackend runs the whole cohort as
-one vmapped program (fl/fleet.py); ShardedFleetBackend runs that same
+one compiled program (fl/fleet.py); ShardedFleetBackend runs that same
 program under shard_map over a mesh's data axis (fl/shard_fleet.py). All
 three agree up to float summation order (tests/test_population.py,
 tests/test_fleet.py). AsyncBufferedBackend (fl/async_rounds.py) drops the
@@ -149,7 +149,8 @@ class SequentialBackend:
 # Fleet backends: CohortResult already satisfies RoundResult
 
 class FleetBackend:
-    """The whole cohort as one vmapped masked-SGD program."""
+    """The whole cohort as one masked-SGD program, clients mapped one at a
+    time."""
     name = "fleet"
 
     def __init__(self, engine: FleetEngine):

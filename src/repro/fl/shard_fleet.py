@@ -1,13 +1,15 @@
 """Sharded cohort executor: the fleet program under shard_map.
 
-fl/fleet.py runs one vmapped masked-SGD program for a whole cohort on one
-device. This module scales that same program over the ``data`` axis of a
+fl/fleet.py runs one masked-SGD program for a whole cohort on one device.
+This module scales that same program over the ``data`` axis of a
 launch/mesh.py mesh: the cohort is split into `n_shards` logical shards of
 equal size, each device runs its shards through the *identical* inner
 cohort program, reduces each shard to the masked-FedAvg sufficient
 statistics (core/aggregate.partial_sums), and a single `jax.lax.psum`
 finishes the hierarchical aggregation. Params and the MaskBank are
-replicated (in_specs P()); only per-client tensors are sharded.
+replicated (in_specs P()); only per-client tensors are sharded, the
+clients' resident samples among them (uploaded once, already split over
+the devices).
 
 Determinism contract: the logical shard count S is part of the *numerical*
 program, independent of the device count D (each device owns S/D shards).
@@ -43,9 +45,10 @@ straggler plans, and params that differ by a few ULP (chip_smoke.py
 --chips 4 holds them to 1e-5).
 
 Everything else (mask bank construction, sim-time draws, CohortResult
-views) is inherited from FleetEngine; only `_execute` changes, plus an
-`aggregate` that applies the already-reduced partials instead of
-recomputing them from gathered deltas.
+views) is inherited from FleetEngine; only `_execute` and the placement of
+the resident samples change, plus an `aggregate` that applies the
+already-reduced partials instead of recomputing them from gathered
+deltas.
 """
 from __future__ import annotations
 
@@ -55,7 +58,7 @@ from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.aggregate import combine_partials, partial_sums
 from repro.fl.client import FleetClient
@@ -76,8 +79,8 @@ def _sharded_cohort_fn(model_cls, mesh, n_shards: int, use_kernels: bool):
     """One compiled program per (model, mesh, shard count): masked local SGD
     for all shards + hierarchical masked-FedAvg partials.
 
-    Signature: run(params, bank, idx, xs, ys, sw, lrs, w, n_steps) where
-    per-client operands carry leading (S, Cs) dims. Returns
+    Signature: run(params, bank, idx, client_x, order, ys, sw, lrs, w,
+    n_steps) where per-client operands carry leading (S, Cs) dims. Returns
     (deltas (S, Cs, ...), shard_partials ((S, ...) num tree + (S, K)
     weights), num tree (param shapes), w_per_mask (K,)) with num/
     w_per_mask already fully reduced (replicated on every device).
@@ -90,17 +93,18 @@ def _sharded_cohort_fn(model_cls, mesh, n_shards: int, use_kernels: bool):
         local = n_shards // d_dev      # shards per device
 
         @functools.partial(jax.jit, static_argnames=("n_steps",))
-        def run(params, bank, idx, xs, ys, sw, lrs, w, n_steps):
+        def run(params, bank, idx, client_x, order, ys, sw, lrs, w, n_steps):
             k = jax.tree.leaves(bank)[0].shape[0]
 
-            def body(p, b, mi, x, y, v, lr, wv):
+            def body(p, b, mi, cx, o, y, v, lr, wv):
                 # block-local leaves: (local, Cs, ...). The shard loop is
                 # Python-unrolled on purpose (bounded by S/D) and each
                 # shard's partials are barriered + materialized — see the
                 # determinism contract in the module docstring.
                 ds, parts = [], []
                 for s in range(local):
-                    d = inner(p, b, mi[s], x[s], y[s], v[s], lr[s], n_steps)
+                    d = inner(p, b, mi[s], cx[s], o[s], y[s], v[s], lr[s],
+                              n_steps)
                     parts.append(jax.lax.optimization_barrier(
                         partial_sums(d, wv[s], mi[s], k)))
                     ds.append(d)
@@ -117,10 +121,10 @@ def _sharded_cohort_fn(model_cls, mesh, n_shards: int, use_kernels: bool):
             f = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(), P(), P("data"), P("data"), P("data"),
-                          P("data"), P("data"), P("data")),
+                          P("data"), P("data"), P("data"), P("data")),
                 out_specs=(P("data"), P("data"), P(), P()),
                 check_vma=False)
-            return f(params, bank, idx, xs, ys, sw, lrs, w)
+            return f(params, bank, idx, client_x, order, ys, sw, lrs, w)
         _SHARDED_CACHE[key] = run
     return _SHARDED_CACHE[key]
 
@@ -174,12 +178,20 @@ class ShardedFleetEngine(FleetEngine):
         self._sharded = _sharded_cohort_fn(model_cls, mesh, n_shards,
                                            self.use_kernels)
 
-    def _execute(self, params, bank, idx, xs, ys, sw, lrs, weights):
-        s, cs = self.n_shards, len(self.clients) // self.n_shards
+    def _split(self, a):
+        """(C, ...) -> (S, Cs, ...): shard s holds clients [s*Cs, (s+1)*Cs)."""
+        return a.reshape((self.n_shards, len(self.clients) // self.n_shards)
+                         + a.shape[1:])
 
-        def resh(a):
-            return a.reshape((s, cs) + a.shape[1:])
-        d, pr, num, wpm = self._sharded(params, bank, resh(idx), resh(xs),
+    def _put_client_data(self, x):
+        return jax.device_put(self._split(x),
+                              NamedSharding(self.mesh, P("data")))
+
+    def _execute(self, params, bank, idx, order, ys, sw, lrs, weights):
+        s, cs = self.n_shards, len(self.clients) // self.n_shards
+        resh = self._split
+        d, pr, num, wpm = self._sharded(params, bank, resh(idx),
+                                        self._client_data(), resh(order),
                                         resh(ys), resh(sw), resh(lrs),
                                         resh(weights), self.steps)
         deltas = jax.tree.map(

@@ -4,7 +4,7 @@ Experiments are described by a typed `SimulationConfig` (workload, backend,
 policy, cohort composition, speed model) so configs can be constructed
 programmatically, validated up front, and carry per-client heterogeneity
 (learning rates, local-epoch counts) that the fleet backends execute as
-vmapped data. The legacy ``build_simulation(workload, **kwargs)`` call
+mapped data. The legacy ``build_simulation(workload, **kwargs)`` call
 shape (deprecated in PR 2) has been removed — `build_simulation` takes a
 SimulationConfig, full stop.
 

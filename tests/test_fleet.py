@@ -259,6 +259,77 @@ def test_ragged_shards_match_sequential(fleet_sim):
         _tree_close(u.delta, ref.delta, atol=2e-5)
 
 
+def _host_staged(clients, steps, bs, n_steps):
+    """The round's batches as the host built them before the clients'
+    samples lived on the device: each client's epoch permutations, its
+    samples taken as x[orders], padded to (steps, bs)."""
+    feat = clients[0].x.shape[1:]
+    xs = np.zeros((len(clients), steps, bs, *feat), np.float32)
+    ys = np.zeros((len(clients), steps, bs), np.int32)
+    sw = np.zeros((len(clients), steps, bs), np.float32)
+    for i, c in enumerate(clients):
+        b = c.eff_batch_size
+        orders = np.concatenate([c._epoch_order()
+                                 for _ in range(c.local_epochs)])
+        x = c.x[orders].reshape(-1, b, *feat)
+        s = x.shape[0]
+        xs[i, :s, :b] = x
+        ys[i, :s, :b] = c.y[orders].reshape(s, b)
+        sw[i, :s, :b] = 1.0
+        sw[i, int(n_steps[i]):] = 0.0
+    return xs, ys, sw
+
+
+def test_gathered_batches_match_host_batches(fleet_sim):
+    """The batches the cohort program gathers on the device from the
+    clients' resident samples are the batches the host used to build, with
+    the same labels and sample weights, and the round leaves every client's
+    RNG where the host-built round left it. Shards of different sizes and
+    batch sizes, a step cap and a padding slot."""
+    model_cls = fleet_sim.model_cls
+    src = fleet_sim.server.engine.clients[0]
+    shape = [(7, 10, 1), (23, 10, 2), (40, 8, 1), (31, 5, 1)]
+
+    def make():
+        return [FleetClient(cid, model_cls, src.x[:n], src.y[:n], speed=1.0,
+                            batch_size=bs, local_epochs=ep, lr=src.lr,
+                            seed=11)
+                for cid, (n, bs, ep) in enumerate(shape)]
+    eng, old = FleetEngine(model_cls, make(), fleet_sim.server.engine
+                           .unit_specs), make()
+    caps = eng.client_steps.copy()
+    caps[1] = 3
+    members = np.array([True, True, False, True])
+    staged = []
+
+    def spy(n_steps=None):
+        staged.append(FleetEngine._stacked_data(eng, n_steps))
+        return staged[-1]
+    eng._stacked_data = spy
+    params = fleet_sim.server.params
+    res = eng.run_cohort(params, {}, n_steps=caps, members=members)
+    (order, ys, sw), = staged
+
+    eff = np.where(members, caps, 0)
+    want_x, want_y, want_sw = _host_staged(old, eng.steps, eng.bs, eff)
+    got_x = np.asarray(jax.jit(jax.vmap(lambda cx, o: cx[o]))(
+        eng._client_data(), order))
+    assert got_x.shape == want_x.shape
+    for i, c in enumerate(old):
+        s, b = c.local_epochs * (c.n_samples // c.eff_batch_size), \
+            c.eff_batch_size
+        np.testing.assert_array_equal(got_x[i, :s, :b], want_x[i, :s, :b])
+    np.testing.assert_array_equal(np.asarray(ys), want_y)
+    np.testing.assert_array_equal(np.asarray(sw), want_sw)
+    n_par = sum(p.size for p in jax.tree.leaves(params))
+    for i, (c, o) in enumerate(zip(eng.clients, old)):
+        if members[i]:
+            assert res.sim_times[c.id] == o.draw_sim_time(1.0, n_par)
+        a, b = c._rng.get_state(), o._rng.get_state()
+        np.testing.assert_array_equal(a[1], b[1])
+        assert a[2:] == b[2:]
+
+
 def test_non_straggler_stats_rows_match_per_client_copies(fleet_sim):
     """The stacked stats come to the host one copy per unit group, and the
     rows equal a per-client copy of each client's slice, in cohort order,
@@ -298,14 +369,15 @@ def test_cohort_program_has_no_grouped_convolutions(model):
     import re
     from repro.models.small import MODELS
     model_cls = MODELS[model]
-    c, s, b = 3, 2, 2
+    c, s, b, rows = 3, 2, 2, 6
     sd = jax.ShapeDtypeStruct
     params = jax.eval_shape(model_cls.init, jax.random.PRNGKey(0))
     p = jax.tree.map(lambda a: sd(a.shape, jnp.float32), params)
     bank = jax.tree.map(lambda a: sd((2,) + a.shape, jnp.float32), params)
     text = _cohort_fn(model_cls).lower(
         p, bank, sd((c,), jnp.int32),
-        sd((c, s, b) + model_cls.input_shape, jnp.float32),
+        sd((c, rows) + model_cls.input_shape, jnp.float32),
+        sd((c, s, b), jnp.int32),
         sd((c, s, b), jnp.int32), sd((c, s, b), jnp.float32),
         sd((c,), jnp.float32), n_steps=s).as_text()
     assert "stablehlo.convolution" in text

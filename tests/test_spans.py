@@ -128,6 +128,20 @@ def test_stats_copies_counter(rounds):
     assert log.counters["fleet.stats_copies"] == groups
 
 
+def test_client_data_uploads_once(rounds):
+    """An engine uploads its clients' samples in its first round and never
+    again; every round still stages its sample orders."""
+    sim, (log0, log1) = rounds["sim"], rounds["logs"]
+    log2 = sim.server.run_round()
+    assert log0.counters["fleet.data_uploads"] == 1
+    assert "fleet.data_upload" in log0.spans
+    for log in (log1, log2):
+        assert log.counters.get("fleet.data_uploads", 0) == 0
+        assert "fleet.data_upload" not in log.spans
+    for log in (log0, log1, log2):
+        assert "fleet.stage" in log.spans
+
+
 def test_threshold_iters_counter(rounds):
     """An independent recount of calibrate_threshold's passes on the stats,
     target and start threshold the round searched with."""

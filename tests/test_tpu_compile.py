@@ -68,14 +68,15 @@ def _assert_kernel(compiled):
 
 @pytest.mark.parametrize("workload", ["femnist_kernel", "femnist_attn"])
 def test_fleet_cohort_program_compiles(one_chip, compiled_kernels, workload):
-    """The fleet cohort program (vmapped masked SGD through the
-    differentiable kernels) for KernelMLP and KernelAttnClassifier."""
+    """The fleet cohort program (masked SGD mapped over the clients,
+    through the differentiable kernels, on batches gathered from the
+    clients' resident samples) for KernelMLP and KernelAttnClassifier."""
     from repro.fl.fleet import _cohort_fn
     from repro.fl.simulation import WORKLOADS
     from repro.models.kernel_models import KERNEL_MODELS
 
     model_cls = KERNEL_MODELS[WORKLOADS[workload][1]]
-    C, steps, bs, K = 8, 2, 10, 2
+    C, steps, bs, K, rows = 8, 2, 10, 2, 30
     params = jax.eval_shape(model_cls.init, jax.random.PRNGKey(0))
 
     def put(tree, lead=()):
@@ -84,7 +85,8 @@ def test_fleet_cohort_program_compiles(one_chip, compiled_kernels, workload):
     run = _cohort_fn(model_cls, use_kernels=True)
     compiled = run.lower(
         put(params), put(params, (K,)), _sds(one_chip, (C,), jnp.int32),
-        _sds(one_chip, (C, steps, bs) + model_cls.input_shape),
+        _sds(one_chip, (C, rows) + model_cls.input_shape),
+        _sds(one_chip, (C, steps, bs), jnp.int32),
         _sds(one_chip, (C, steps, bs), jnp.int32),
         _sds(one_chip, (C, steps, bs)), _sds(one_chip, (C,)),
         n_steps=steps).compile()
