@@ -1,4 +1,8 @@
-"""Async buffered rounds vs the synchronous barrier -> BENCH_async.json.
+"""Async buffered rounds vs the synchronous barrier, in simulated seconds.
+
+Writes experiments/async_time_to_accuracy.json. Run from the repo root:
+
+    PYTHONPATH=src python -m experiments.async_time_to_accuracy [--smoke]
 
 The claim under test (DESIGN.md §13): under heavy-tailed client latencies,
 asynchronous buffered aggregation (fl/async_rounds.py) reaches the
@@ -159,8 +163,7 @@ def main(argv):
         "device": jax.devices()[0].platform,
         "results": [sync, async_row],
     }
-    out = (pathlib.Path(__file__).resolve().parent.parent
-           / "BENCH_async.json")
+    out = pathlib.Path(__file__).resolve().with_suffix(".json")
     out.write_text(json.dumps(payload, indent=2) + "\n")
     print(json.dumps(payload, indent=2))
 

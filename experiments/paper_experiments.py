@@ -1,8 +1,7 @@
 """Paper-experiment drivers — one function per FLuID table/figure.
 
-Each returns a dict of results; benchmarks/run.py prints the CSV summary and
-experiments/run_paper_validation.py runs the bigger validation pass whose
-numbers land in EXPERIMENTS.md §Paper-validation.
+Each returns a dict of results; experiments/run_paper_validation.py runs
+them and writes experiments/paper_validation.json.
 """
 from __future__ import annotations
 

@@ -10,7 +10,7 @@ import json
 import sys
 import time
 
-from benchmarks import paper_experiments as pe
+from experiments import paper_experiments as pe
 
 FULL = "--full" in sys.argv
 OUT = "experiments/paper_validation.json"

@@ -1,5 +1,3 @@
 from repro.configs.base import ModelConfig, get_config, all_configs, ARCH_IDS
-from repro.configs.shapes import INPUT_SHAPES, input_specs
 
-__all__ = ["ModelConfig", "get_config", "all_configs", "ARCH_IDS",
-           "INPUT_SHAPES", "input_specs"]
+__all__ = ["ModelConfig", "get_config", "all_configs", "ARCH_IDS"]

@@ -1,7 +1,7 @@
 """Model configuration schema + registry.
 
 Every assigned architecture registers a ``ModelConfig`` here; the launcher,
-dry-run, smoke tests and FL integration all consume the same object.
+smoke tests and FL integration all consume the same object.
 """
 from __future__ import annotations
 

@@ -2,8 +2,8 @@
 
 48 layers, d_model=8192, 64 heads (GQA kv=8), d_ff=22016 (SwiGLU),
 vocab 65536 including VQ-VAE image-token codes. Early fusion means the
-"vision frontend" is the VQ tokenizer — inputs are already token ids, so
-input_specs supplies interleaved text+image token ids.
+"vision frontend" is the VQ tokenizer — inputs are already token ids
+(interleaved text+image).
 """
 from repro.configs.base import ModelConfig
 

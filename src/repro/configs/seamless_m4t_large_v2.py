@@ -3,8 +3,8 @@
 Enc-dec transformer: 24 encoder + 24 decoder layers ("24L" in the assignment
 is read as the per-stack depth of the published large-v2 card), d_model=1024,
 16 heads (GQA kv=16 == MHA), d_ff=8192 (ReLU, non-gated), vocab 256206.
-The speech frontend (mel + conformer feature extractor) is a stub: input_specs
-provides frame embeddings (B, S, d_model) directly.
+The speech frontend (mel + conformer feature extractor) is a stub: the encoder
+takes frame embeddings (B, S, d_model) directly.
 """
 from repro.configs.base import ModelConfig
 

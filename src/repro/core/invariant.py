@@ -150,20 +150,3 @@ def calibrate_threshold(per_client_stats, n_drop_target: int, th0: float,
             return th
         th *= TH_GROWTH
     return th
-
-
-def calibrate_threshold_per_group(per_client_stats, drop_targets: Dict[str, int],
-                                  th0: float, max_iters: int = 200
-                                  ) -> Dict[str, float]:
-    """Per-layer thresholds (paper: 'FLuID can have a different drop
-    threshold for each layer')."""
-    stacked = _stack(per_client_stats)
-    out = {}
-    for g, target in drop_targets.items():
-        th = max(float(th0), EPS)
-        for _ in range(max_iters):
-            if _n_invariant({g: stacked[g]}, th) >= target:
-                break
-            th *= TH_GROWTH
-        out[g] = th
-    return out

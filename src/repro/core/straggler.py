@@ -178,7 +178,7 @@ class ArrivalModel:
 
       * `tail_sigma`  — extra multiplicative lognormal spread applied only
         to async arrivals (network variance not visible to a barrier that
-        already waits for the max). Usually 0.0 for fair benchmarks.
+        already waits for the max). Usually 0.0 for fair comparisons.
       * `drop_prob`   — per-dispatch probability the client falls off
         mid-round. A dropped client is NOT lost: it reconnects after an
         Exp(reconnect_mean) pause, resumes from where it stopped, and its

@@ -47,8 +47,7 @@ WORKLOADS = {
     "femnist_kernel": ("femnist", "kernel_mlp", 0.02, 10),
     "femnist_attn": ("femnist", "kernel_attn", 0.02, 10),
     # population-scale workload: 32-dim vector MLP, small enough that a
-    # 5k-client cohort's stacked batches stay ~64 MB (benchmarks/
-    # population_bench.py)
+    # 5k-client cohort's stacked batches stay ~64 MB
     "synth": ("synth", "synth_mlp", 0.05, 20),
 }
 

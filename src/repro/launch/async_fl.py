@@ -6,7 +6,7 @@ asynchronous buffered backend against a device-resident `ClientStore`,
 and prints per-buffer progress (virtual clock, staleness, dropouts).
 ``--backend fleet`` runs the synchronous barrier with the *same*
 population and latency distribution, so the two invocations form the
-BENCH_async.json comparison by hand.
+experiments/async_time_to_accuracy.py comparison by hand.
 
 CPU-friendly smoke:
 

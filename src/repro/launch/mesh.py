@@ -1,23 +1,11 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 A function (not a module-level constant) so importing never touches jax
-device state. The dry-run launcher sets XLA_FLAGS before any jax import to
-materialize 512 host placeholder devices.
+device state.
 """
 from __future__ import annotations
 
 import jax
-
-
-def _mesh(shape, axes):
-    return jax.make_mesh(shape, axes,
-                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -25,4 +13,5 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    return _mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -4,9 +4,9 @@ Default path: launch/serving.ServeEngine — one compiled decode chunk serves
 a queue of requests with mixed dropout rates, prompt lengths, and generation
 lengths (see that module's docstring). ``--baseline`` instead runs the
 original synchronous path (one Python-loop token at a time, whole batch in
-lockstep) — kept as the reference the engine is benchmarked against in
-benchmarks/serve_bench.py. On CPU runs the smoke config; on a real mesh the
-steps inherit the launch shardings.
+lockstep) — kept as the engine's reference and the encoder-decoder
+fallback. On CPU runs the smoke config; on a real mesh the steps inherit the
+launch shardings.
 """
 from __future__ import annotations
 

@@ -311,11 +311,3 @@ def param_pspecs(params, kv_shardable: bool = True, fsdp: bool = True):
             spec = improve(prefix, tree.shape, spec)
         return spec
     return walk(params, "")
-
-
-def param_shardings(params, mesh: Mesh, kv_shardable: bool = True,
-                    fsdp: bool = True):
-    with mesh_context(mesh):
-        specs = param_pspecs(params, kv_shardable, fsdp=fsdp)
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                        is_leaf=lambda x: isinstance(x, P))

@@ -1,104 +1,20 @@
-"""Step builders: train_step / prefill_step / serve_step with shardings.
+"""Step builders: train_step / prefill_step / serve_step.
 
-These are the functions the dry-run lowers and the drivers execute. All take
-the mesh through launch.sharding.mesh_context; in/out shardings are derived
-from the same rule table the model's internal constraints use.
+These are the functions the drivers execute. They take the mesh through
+launch.sharding.mesh_context; the model's internal sharding constraints
+read it from there.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.launch import sharding as shlib
 from repro.models import model as model_lib
 from repro.optim import make_optimizer
-
-
-# ---------------------------------------------------------------------------
-# sharding helpers
-
-def _div(n: int, sizes) -> bool:
-    t = 1
-    for s in sizes:
-        t *= s
-    return n % t == 0 and n >= t
-
-
-def batch_spec_sym(mesh, batch: int):
-    """'B' if the global batch divides the batch axes, else None (replicate)."""
-    ax = shlib.batch_axes(mesh)
-    total = 1
-    for a in ax:
-        total *= mesh.shape[a]
-    return "B" if batch % total == 0 and batch >= total else None
-
-
-def batch_shardings(cfg: ModelConfig, mesh, batch_tree):
-    b = None
-
-    def leaf(s):
-        sym = batch_spec_sym(mesh, s.shape[0])
-        tail = (None,) * (len(s.shape) - 1)
-        with shlib.mesh_context(mesh):
-            return NamedSharding(mesh, shlib.pspec(sym, *tail))
-    return jax.tree.map(leaf, batch_tree)
-
-
-def cache_shardings(cfg: ModelConfig, mesh, cache_tree, seq_shard=False):
-    """Cache leaves are (R, B, ...) stacked. Shard batch; shard the KV-head
-    or head-dim axis of attention caches on 'model' when divisible.
-
-    seq_shard=True instead shards the cache *sequence* axis over 'model'
-    (cross-device flash-decoding: GSPMD turns the softmax over the sharded
-    axis into tiny stat psums instead of gathering the cache — §Perf)."""
-    tp = mesh.shape.get("model", 1)
-
-    def leaf(s):
-        shape = s.shape
-        bsym = batch_spec_sym(mesh, shape[1]) if len(shape) >= 2 else None
-        spec = [None, bsym] + [None] * (len(shape) - 2)
-        # attention KV cache: (R, B, C, KV, hd)
-        if (len(shape) == 5 and shape[3] == cfg.n_kv_heads
-                and cfg.head_dim == shape[4]):
-            if seq_shard and shape[2] % tp == 0 and shape[2] >= tp:
-                spec[2] = "M"
-            elif shape[3] % tp == 0:
-                spec[3] = "M"
-            elif shape[4] % tp == 0:
-                spec[4] = "M"    # head-dim sharding (MQA-style decode TP)
-        # latent/channel caches (R, B, C, r): MLA c_kv/k_rope, conv history —
-        # shard the channel dim (contractions psum; elementwise stays local)
-        elif (len(shape) == 4 and s.dtype != jnp.int32
-              and shape[3] % tp == 0 and shape[3] >= 2 * tp):
-            if seq_shard and shape[2] % tp == 0 and shape[2] >= tp:
-                spec[2] = "M"
-            else:
-                spec[3] = "M"
-        elif (seq_shard and len(shape) == 3 and s.dtype == jnp.int32
-              and shape[2] % tp == 0 and shape[2] >= tp):
-            spec[2] = "M"        # ring slot positions follow the cache
-        with shlib.mesh_context(mesh):
-            return NamedSharding(mesh, shlib.pspec(*spec))
-    return jax.tree.map(leaf, cache_tree)
-
-
-def opt_state_shardings(mesh, opt_specs, param_shards):
-    """Optimizer state mirrors param sharding; scalars replicated."""
-    def leaf(path_shape, ps):
-        return ps
-    # opt state structure: {"m": params-like, "v": params-like, "t": scalar}
-    out = {}
-    for k, v in opt_specs.items():
-        if k in ("m", "v"):
-            out[k] = param_shards
-        else:
-            out[k] = NamedSharding(mesh, P())
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -174,58 +90,3 @@ def make_serve_step(cfg: ModelConfig, window_override: Optional[int] = None,
         return logits[:, -1], new_caches
     return step
 
-
-# ---------------------------------------------------------------------------
-# lowering assembly
-
-def mask_specs_and_shardings(cfg: ModelConfig, mesh):
-    """ShapeDtypeStructs + shardings for FLuID sub-model masks."""
-    from repro.core import transformer_hooks as hooks
-    with shlib.mesh_context(None):
-        masks = hooks.full_masks(cfg)
-    spec = jax.tree.map(
-        lambda m: jax.ShapeDtypeStruct(m.shape, m.dtype), masks)
-    with shlib.mesh_context(mesh):
-        shard = jax.tree.map(
-            lambda m: NamedSharding(mesh, shlib.guarded_pspec(
-                mesh, m.shape, (None,) * (len(m.shape) - 1) + ("M",),
-                strict=True)), spec)
-    return spec, shard
-
-
-def shardings_for(cfg: ModelConfig, mesh, mode: str, specs: dict,
-                  window_override=None, fsdp: bool = True,
-                  cache_seq_shard: bool = False):
-    kw_seq_shard = {"on": cache_seq_shard}
-    """(in_shardings, out_shardings, arg ShapeDtypeStructs) for jit.lower."""
-    param_sp = model_lib.param_specs(cfg)
-    kv_ok = cfg.n_kv_heads % mesh.shape.get("model", 1) == 0
-    pshard = shlib.param_shardings(param_sp, mesh, kv_shardable=kv_ok,
-                                   fsdp=fsdp)
-
-    if mode == "train":
-        opt = make_optimizer(cfg.optimizer)
-        opt_sp = jax.eval_shape(opt.init, param_sp)
-        oshard = opt_state_shardings(mesh, opt_sp, pshard)
-        bshard = batch_shardings(cfg, mesh, specs["batch"])
-        args = (param_sp, opt_sp, specs["batch"])
-        in_sh = (pshard, oshard, bshard)
-        out_sh = (pshard, oshard, None)
-        return in_sh, out_sh, args
-
-    if mode == "prefill":
-        bshard = batch_shardings(cfg, mesh, specs["batch"])
-        args = (param_sp, specs["batch"])
-        in_sh = (pshard, bshard)
-        return in_sh, None, args
-
-    if mode == "decode":
-        cshard = cache_shardings(cfg, mesh, specs["caches"],
-                                 seq_shard=kw_seq_shard.get("on", False))
-        tshard = batch_shardings(cfg, mesh, {"t": specs["token"],
-                                             "p": specs["pos"]})
-        args = (param_sp, specs["caches"], specs["token"], specs["pos"])
-        in_sh = (pshard, cshard, tshard["t"], tshard["p"])
-        return in_sh, None, args
-
-    raise ValueError(mode)

@@ -111,9 +111,3 @@ def cache_specs(cfg: ModelConfig, batch, seq_len, window_override=None):
 
 def count_params(params) -> int:
     return sum(x.size for x in jax.tree.leaves(params))
-
-
-def param_specs(cfg: ModelConfig, key=None):
-    """ShapeDtypeStruct pytree of the params (no allocation)."""
-    key = jax.random.PRNGKey(0) if key is None else key
-    return jax.eval_shape(lambda k: init_params(cfg, k), key)

@@ -4,9 +4,8 @@ Parallelism: tensor-parallel experts — every device holds *all* experts with a
 1/16 slice of the expert hidden dim ("model" axis). Token dispatch (top-k,
 sort, ragged grouped matmul) is therefore local to each data shard; the only
 collective is the same all-reduce a dense TP FFN needs. This sidesteps
-all-to-all dispatch entirely (see EXPERIMENTS.md §Perf for the comparison
-discussion) and is implemented with shard_map so ragged_dot never has to be
-GSPMD-partitioned.
+all-to-all dispatch entirely and is implemented with shard_map so ragged_dot
+never has to be GSPMD-partitioned.
 
 Invariant-Dropout hooks:
   expert_mask  (E,)   -- 0 drops a whole expert (router logit -> -inf)
@@ -84,8 +83,7 @@ def _moe_tokens(p, x2d, cfg: ModelConfig, neuron_mask, expert_mask,
     size cap = ceil(T*k/E * CAPACITY_FACTOR) and processed with one dense
     (E, cap, d) x (E, d, f) einsum — the XLA-portable grouped matmul
     (overflow tokens drop, standard capacity semantics). impl "ragged" uses
-    jax.lax.ragged_dot (efficient on TPU; XLA:CPU expands it densely, so the
-    dry-run uses capacity).
+    jax.lax.ragged_dot (efficient on TPU; XLA:CPU expands it densely).
     """
     dt = cdtype(cfg)
     T, d = x2d.shape

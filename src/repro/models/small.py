@@ -201,8 +201,8 @@ class ShakespeareLSTM:
 # ---------------------------------------------------------------------------
 # Population-scale probe model: 32-dim vector in, one droppable hidden layer.
 # Small on purpose — a 5k-client cohort's stacked deltas stay a few hundred
-# MB short of anything interesting, so benchmarks/population_bench.py can
-# sweep cohort sizes from a 100k-client store on one host.
+# MB short of anything interesting, so population runs can sample cohorts
+# of thousands from a 100k-client store on one host.
 
 class SynthMLP:
     num_classes = 10

@@ -219,14 +219,21 @@ def _fleet_pair(model_cls, keep_maps, seed=0):
     return params, rd, rk
 
 
-@pytest.mark.parametrize("model_name", ["kernel_mlp", "kernel_attn"])
-def test_fleet_use_kernels_matches_dense(model_name):
+@pytest.mark.parametrize("model_name,kept", [
+    pytest.param("kernel_mlp", 512, id="kernel_mlp"),
+    pytest.param("kernel_attn", None, id="kernel_attn"),
+    # the KernelMLP's 1024-wide FFN at dropout rates 0, 0.25 and 0.75
+    pytest.param("kernel_mlp", 1024, id="kernel_mlp-r0"),
+    pytest.param("kernel_mlp", 768, id="kernel_mlp-r0.25"),
+    pytest.param("kernel_mlp", 256, id="kernel_mlp-r0.75"),
+])
+def test_fleet_use_kernels_matches_dense(model_name, kept):
     """Acceptance gate: `use_kernels=True` cohort == dense cohort —
     deltas, sim-times, and aggregation (interpret mode)."""
     from repro.models.kernel_models import KERNEL_MODELS
     model_cls = KERNEL_MODELS[model_name]
     if model_name == "kernel_mlp":
-        keep_maps = {0: {"ffn": np.arange(512)}, 1: {"ffn": np.arange(512)}}
+        keep_maps = {0: {"ffn": np.arange(kept)}, 1: {"ffn": np.arange(kept)}}
     else:
         keep_maps = {0: {"heads": np.arange(2), "ffn": np.arange(128)},
                      1: {"heads": np.arange(2), "ffn": np.arange(128)}}
