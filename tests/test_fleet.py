@@ -358,6 +358,23 @@ def test_non_straggler_stats_rows_match_per_client_copies(fleet_sim):
             np.testing.assert_array_equal(got_row[g], want_row[g])
 
 
+def _lowered_cohort_text(model):
+    """The cohort program of `model`, lowered at a tiny cohort."""
+    from repro.models.small import MODELS
+    model_cls = MODELS[model]
+    c, s, b, rows = 3, 2, 2, 6
+    sd = jax.ShapeDtypeStruct
+    params = jax.eval_shape(model_cls.init, jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda a: sd(a.shape, jnp.float32), params)
+    bank = jax.tree.map(lambda a: sd((2,) + a.shape, jnp.float32), params)
+    return _cohort_fn(model_cls).lower(
+        p, bank, sd((c,), jnp.int32),
+        sd((c, rows) + model_cls.input_shape, jnp.float32),
+        sd((c, s, b), jnp.int32),
+        sd((c, s, b), jnp.int32), sd((c, s, b), jnp.float32),
+        sd((c,), jnp.float32), n_steps=s).as_text()
+
+
 @pytest.mark.parametrize("model", ["femnist_cnn", "cifar_vgg9"])
 def test_cohort_program_has_no_grouped_convolutions(model):
     """Each client's convolutions run at the client's own shapes. Batched
@@ -367,19 +384,18 @@ def test_cohort_program_has_no_grouped_convolutions(model):
     gradients at 64 clients). The lowered program is checked here on the
     CPU; the wrong numbers themselves show only on the chip."""
     import re
-    from repro.models.small import MODELS
-    model_cls = MODELS[model]
-    c, s, b, rows = 3, 2, 2, 6
-    sd = jax.ShapeDtypeStruct
-    params = jax.eval_shape(model_cls.init, jax.random.PRNGKey(0))
-    p = jax.tree.map(lambda a: sd(a.shape, jnp.float32), params)
-    bank = jax.tree.map(lambda a: sd((2,) + a.shape, jnp.float32), params)
-    text = _cohort_fn(model_cls).lower(
-        p, bank, sd((c,), jnp.int32),
-        sd((c, rows) + model_cls.input_shape, jnp.float32),
-        sd((c, s, b), jnp.int32),
-        sd((c, s, b), jnp.int32), sd((c, s, b), jnp.float32),
-        sd((c,), jnp.float32), n_steps=s).as_text()
+    text = _lowered_cohort_text(model)
     assert "stablehlo.convolution" in text
     groups = re.findall(r"(?:feature|batch)_group_count = (\d+)", text)
     assert groups and set(groups) == {"1"}, sorted(set(groups))
+
+
+@pytest.mark.parametrize("model", ["femnist_cnn", "cifar_vgg9"])
+def test_cohort_program_has_no_select_and_scatter(model):
+    """The max-pools' gradient is a selection of elementwise ops
+    (models/small._pool). reduce_window's own gradient lowers to a
+    select_and_scatter, which a TPU v5e runs as a windowed scatter at about
+    twice the time of an elementwise pass over the same tensor."""
+    text = _lowered_cohort_text(model)
+    assert "stablehlo.convolution" in text
+    assert "select_and_scatter" not in text
