@@ -11,11 +11,19 @@ running system under test, driven through its public entry points.
 The benchmark makes the initial weights itself, on the device in one jitted
 call from the seed, and hands them to the server. Everything else the
 program draws from the seed it is given.
+
+The model is the configuration's on both sides. The benchmark's side is its
+family, bench/reference/<family>.py (init, rows, work counts; the CNN
+family's module docstring gives the whole contract). The program's side is
+its client model, `program_model`, in one of two forms: a key of
+`repro.models.small.MODELS`, or `"package.module:Attr"`, a model class
+imported from the program's source by that path.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
 from typing import Dict, List
 
 import jax
@@ -23,6 +31,15 @@ import numpy as np
 
 from bench.harness.data import cohort_data
 from bench.reference.fluid_ref import RoundRecord
+
+
+def program_model(name: str):
+    """The program's client model class a configuration names."""
+    if ":" in name:
+        module, attr = name.split(":", 1)
+        return getattr(importlib.import_module(module), attr)
+    from repro.models.small import MODELS
+    return MODELS[name]
 
 
 def program_seed(seed: int) -> int:
@@ -61,9 +78,8 @@ class Program:
         from repro.fl.client import FleetClient
         from repro.fl.population import ClientStore
         from repro.fl.rounds import make_backend
-        from repro.models.small import MODELS
         t, cfg = self.traffic, self.config
-        model_cls = MODELS[cfg["program_model"]]
+        model_cls = program_model(cfg["program_model"])
         x, y, parts, speeds = cohort_data(cfg, t, pseed)
         n = len(parts)
         clients = [FleetClient(i, model_cls, x[parts[i]], y[parts[i]],
@@ -131,14 +147,15 @@ class Program:
 
     def warm_sub_model_counts(self, lo: int, hi: int):
         """Compile what a later round can meet that the set-up rounds may
-        not have. The non-straggler statistics and the calibration compile
-        anew for every count of clients that trained a sub-model (the
-        program gathers the others' deltas and stacks their statistics
-        one array per client), and that count moves from round to round
-        as clients near the straggler band flip. So run one cohort round
+        not have. The gather of the non-straggler clients' deltas and the
+        statistics program over them compile anew for every count of
+        clients that trained a sub-model (the non-stragglers' count is
+        their leading axis), and that count moves from round to round as
+        clients near the straggler band flip. So run one cohort round
         through the public backend API with a sub-model in the bank, then
-        the statistics and calibration with lo..hi of its clients counted
-        as sub-model clients. The server's state is untouched."""
+        the statistics with lo..hi of its clients counted as sub-model
+        clients, each followed by the calibration's host passes (NumPy,
+        which compile nothing). The server's state is untouched."""
         from repro.core import invariant as inv
         from repro.fl.rounds import make_backend
         if self.population:

@@ -6,9 +6,10 @@ follows them from the same initial weights. Numbers compared, each against
 its limit in bench/limits/<cell>.json:
 
   loss_gap       each round's loss, |L_prog - L_ref| / L_ref at worst: the
-                 mean cross-entropy of the global params after the round on
-                 a seeded sample of the cell's training rows, evaluated by
-                 the reference's float32 forward pass at "highest";
+                 mean of the family's `example_loss` (for the CNN family,
+                 cross-entropy) of the global params after the round on a
+                 seeded sample of the cell's training rows, evaluated in
+                 float32 at "highest";
   update_gap     the first round's update of the global params (FedAvg's
                  gradient as the server applies it), by the worst leaf:
                  | ||d_prog|| - ||d_ref|| | / max(||d_ref||, median leaf's);
@@ -60,17 +61,15 @@ _LOSS = {}
 
 
 def _loss_fn(family, config):
-    if config["name"] in _LOSS:
-        return _LOSS[config["name"]]
+    key = (family.__name__, config["name"])
+    if key in _LOSS:
+        return _LOSS[key]
 
     @jax.jit
     def loss(params, x, y):
-        logits = family.forward(config, params, x,
-                                jax.lax.Precision.HIGHEST)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
-        return jnp.sum(lse - gold)
-    _LOSS[config["name"]] = loss
+        return jnp.sum(family.example_loss(config, params, x, y,
+                                           jax.lax.Precision.HIGHEST))
+    _LOSS[key] = loss
     return loss
 
 

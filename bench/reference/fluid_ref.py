@@ -1,11 +1,14 @@
 """Plain reference of FLuID's synchronous rounds (Algorithm 1), client by client.
 
-Given a configuration (the model), a traffic mix (cohort, data, stragglers)
-and a seed, it takes the benchmark's own inputs for a fixed cohort
+Given a configuration, a traffic mix (cohort, data, stragglers) and a seed,
+it takes the benchmark's own inputs for a fixed cohort
 (bench/harness/data.py), or rebuilds a population's data, registry and
 cohorts from the seeded generators the system under test documents, and
 the clients' batch orders and latency draws from their documented seeded
-streams. Then it runs each round literally:
+streams. The model comes from the configuration's family
+(bench/reference/<family>.py, whose docstring gives the contract): its
+per-example loss, its unit groups, sub-model extraction, embedding and
+unit statistics. Then it runs each round literally:
 
   1. every client of the cohort trains with minibatch SGD, one client at a
      time; a straggler trains the physically extracted sub-model that the
@@ -19,8 +22,9 @@ streams. Then it runs each round literally:
 
 It imports nothing of the program. `variant` plants the two deviations its
 comparison has to catch: "bf16" computes local SGD in bfloat16 (the control:
-the precision step below the configuration's float32), and "half_batch"
-leaves half of every batch out and takes the mean over the rest.
+the precision step below the configuration's float32; integer inputs such
+as token ids are left as they are), and "half_batch" leaves half of every
+batch out and takes the mean over the rest.
 """
 from __future__ import annotations
 
@@ -31,8 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.harness.data import cohort_data
-from bench.reference import seq_cnn
+from bench.harness.data import cohort_data, family_of
 
 VARIANTS = ("reference", "bf16", "half_batch")
 TH_GROWTH = 1.25
@@ -45,7 +48,8 @@ EMA_DECAY = 0.5
 def image_data(shape, num_classes: int, n: int, n_writers: int, seed: int):
     """(x, y, writer) of the synthetic image set: class prototypes plus a
     writer style plus noise. Only the training split is drawn; the test
-    split comes after it in the stream and is not needed."""
+    split comes after it in the stream and is not needed. The population
+    entry draws its data here, so it serves image families only."""
     rng = np.random.RandomState(seed)
     protos = rng.randn(num_classes, *shape).astype(np.float32)
     styles = 0.6 * rng.randn(n_writers, *shape).astype(np.float32)
@@ -125,8 +129,10 @@ def _n_invariant(stats, th: float) -> int:
 
 @dataclass
 class InvariantPolicy:
-    """Threshold calibration and the EMA-ranked choice of dropped units."""
+    """Threshold calibration and the EMA-ranked choice of dropped units
+    over the unit groups of the configuration's family."""
     cfg: dict
+    family: object
     th: Optional[float] = None
     votes: Optional[Dict[str, np.ndarray]] = None
     means: Optional[Dict[str, np.ndarray]] = None
@@ -136,8 +142,9 @@ class InvariantPolicy:
             mins = np.asarray([np.concatenate([v.ravel() for v in s.values()])
                                .min() for s in stats], np.float32)
             self.th = float(np.mean(mins))
-        target = sum(size - seq_cnn.keep_count(size, rate)
-                     for _, size in seq_cnn.droppable(self.cfg))
+        fam = self.family
+        target = sum(size - fam.keep_count(size, rate)
+                     for _, size in fam.droppable(self.cfg))
         if target:
             th = max(float(self.th), 1e-8)
             for _ in range(200):
@@ -160,8 +167,8 @@ class InvariantPolicy:
 
     def keep_map(self, rate: float) -> Dict[str, np.ndarray]:
         out = {}
-        for name, size in seq_cnn.droppable(self.cfg):
-            k = seq_cnn.keep_count(size, rate)
+        for name, size in self.family.droppable(self.cfg):
+            k = self.family.keep_count(size, rate)
             if self.votes is None:
                 out[name] = np.arange(k)
                 continue
@@ -174,17 +181,14 @@ class InvariantPolicy:
 # ---------------------------------------------------------------------------
 # local SGD
 
-def _sgd_program(cfg, variant: str):
+def _sgd_program(family, cfg, variant: str):
     dtype = jnp.bfloat16 if variant == "bf16" else jnp.float32
     precision = (jax.lax.Precision.DEFAULT if variant == "bf16"
                  else jax.lax.Precision.HIGHEST)
     half = variant == "half_batch"
 
     def loss(p, xb, yb):
-        logits = seq_cnn.forward(cfg, p, xb, precision).astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, yb[:, None], axis=-1)[:, 0]
-        return jnp.mean(lse - gold)
+        return jnp.mean(family.example_loss(cfg, p, xb, yb, precision))
 
     @jax.jit
     def train(params, xs, ys, n_steps, lr):
@@ -192,7 +196,9 @@ def _sgd_program(cfg, variant: str):
         p0 = jax.tree.map(lambda a: a.astype(dtype), params)
 
         def step(i, p):
-            xb, yb = xs[i].astype(dtype), ys[i]
+            xb, yb = xs[i], ys[i]
+            if jnp.issubdtype(xb.dtype, jnp.floating):
+                xb = xb.astype(dtype)
             if half:
                 xb, yb = xb[:xb.shape[0] // 2], yb[:yb.shape[0] // 2]
             g = jax.grad(loss)(p, xb, yb)
@@ -236,6 +242,7 @@ class Reference:
         if self.variant not in VARIANTS:
             raise ValueError(f"variant must be one of {VARIANTS}")
         t, cfg = self.traffic, self.cfg
+        self.family = family_of(cfg)
         ds = cfg["dataset"]
         self.lr = float(cfg["lr"])
         self.bs = int(cfg["batch_size"])
@@ -251,9 +258,10 @@ class Reference:
         else:
             n_parts = int(t["n_partitions"])
             n_data = n_parts * int(t["samples_per_partition"])
-            self.x, self.y, _ = image_data(
+            self.x, y, _ = image_data(
                 tuple(cfg["input_shape"]), cfg["num_classes"], n_data,
                 n_parts, self.seed + ds["seed_offset"])
+            self.y = y.astype(np.int32)
             self._parts = partition_even(n_data, n_parts, self.seed)
             n = int(t["n_clients"])
             self._speeds = population_speeds(n, t["straggler_frac_pop"],
@@ -262,8 +270,8 @@ class Reference:
                 0, n_parts, size=n)
             spp = int(t["samples_per_partition"])
             self.s_max = self.epochs * (spp // min(self.bs, spp))
-        self.policy = InvariantPolicy(cfg)
-        self._train = _sgd_program(cfg, self.variant)
+        self.policy = InvariantPolicy(cfg, self.family)
+        self._train = _sgd_program(self.family, cfg, self.variant)
 
     def _cohort(self, rnd: int) -> List[_Client]:
         t = self.traffic
@@ -285,14 +293,14 @@ class Reference:
         orders = [c.rng.permutation(n)[:nb * bs] for _ in range(self.epochs)]
         rows = c.idx[np.concatenate(orders)]
         steps = self.epochs * nb
-        xs = np.zeros((self.s_max, bs) + self.x.shape[1:], np.float32)
-        ys = np.zeros((self.s_max, bs), np.int32)
+        xs = np.zeros((self.s_max, bs) + self.x.shape[1:], self.x.dtype)
+        ys = np.zeros((self.s_max, bs) + self.y.shape[1:], self.y.dtype)
         xs[:steps] = self.x[rows].reshape(steps, bs, *self.x.shape[1:])
-        ys[:steps] = self.y[rows].reshape(steps, bs)
+        ys[:steps] = self.y[rows].reshape(steps, bs, *self.y.shape[1:])
         return xs, ys, steps
 
     def run(self, params, rounds: int) -> List[RoundRecord]:
-        cfg, t = self.cfg, self.traffic
+        cfg, t, fam = self.cfg, self.traffic, self.family
         rate_sub = float(t["fixed_rate"])
         params = jax.tree.map(lambda a: np.asarray(a, np.float32), params)
         out = []
@@ -308,15 +316,15 @@ class Reference:
                 xs, ys, steps = self._batches(c)
                 r = rates[c.cid]
                 start = (params if r >= 1.0
-                         else seq_cnn.extract(cfg, params, keep))
+                         else fam.extract(cfg, params, keep))
                 d = self._train(start, xs, ys, steps, np.float32(self.lr))
                 d = jax.tree.map(np.asarray, d)
                 if r >= 1.0:
                     mask = jax.tree.map(np.ones_like, params)
                     new = jax.tree.map(np.add, params, d)
-                    stats.append(seq_cnn.unit_stats(cfg, params, new))
+                    stats.append(fam.unit_stats(cfg, params, new))
                 else:
-                    d, mask = seq_cnn.embed(cfg, params, d, keep)
+                    d, mask = fam.embed(cfg, params, d, keep)
                 n_par = sum(a.size for a in jax.tree.leaves(start))
                 w = np.float32(len(c.idx))
                 num = jax.tree.map(lambda a, dd, m: a + w * m * dd,

@@ -1,5 +1,37 @@
 """Plain reference of a sequential CNN described by a configuration file.
 
+This module is one model family of the benchmark. A configuration names
+its family (`"family": "<name>"`), and everything the benchmark does that
+depends on the model goes through `bench/reference/<name>.py`: the
+harness (`bench/run.py`, `bench/harness/`) and the round reference
+(`bench/reference/fluid_ref.py`) import no family by name. A family
+provides:
+
+  init(cfg, key)                      full-width float32 parameters
+  forward(cfg, params, x, precision)  the model's output on a batch
+  example_loss(cfg, params, x, y, precision) -> (B,) float32
+                                      each example's loss; the round
+                                      reference's SGD takes its mean, the
+                                      correctness check its sum
+  rows(cfg, rng, n_clients, samples_per_client, writers_per_client)
+                                      -> (x, y), a fixed cohort's data
+                                      drawn from a np.random.Generator,
+                                      client after client
+  droppable(cfg), keep_count(size, rate)
+                                      the unit groups a sub-model may
+                                      thin and the units it keeps
+  extract(cfg, params, keep), embed(cfg, full_like, sub_delta, keep)
+                                      a sub-model's weights, and its delta
+                                      back in full coordinates with a mask
+  unit_stats(cfg, prev, new)          per group, per unit: its relative
+                                      update (invariant-dropout statistics)
+  param_count(cfg, rate), train_flops(cfg, rate), sample_bytes(cfg)
+                                      the work counts of the metrics
+
+A family may take any input and label shapes and dtypes: the round
+reference keeps `x`'s and `y`'s as `rows` makes them (token ids stay
+integers, a label per position stays one).
+
 A configuration lists its layers in order: `conv` layers (k x k kernel,
 stride 1, SAME padding, ReLU, an optional 2x2 max-pool) and then `dense`
 layers (ReLU on every one but the last). Convolution outputs are flattened
@@ -102,6 +134,36 @@ def forward(cfg, params, x, precision=jax.lax.Precision.HIGHEST):
             if i < n - 1:
                 x = jax.nn.relu(x)
     return x
+
+
+def example_loss(cfg, params, x, y, precision=jax.lax.Precision.HIGHEST):
+    """(B,) float32 cross-entropy of each example's logits against its
+    class label."""
+    logits = forward(cfg, params, x, precision).astype(jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+    return lse - gold
+
+
+def rows(cfg, rng, n_clients: int, samples_per_client: int,
+         writers_per_client: int):
+    """(x, y): float32 images and int32 class labels of a fixed cohort,
+    `samples_per_client` rows a client, client after client. A row is a
+    class prototype, plus the style of one of the client's own
+    `writers_per_client` writers, plus noise; labels are uniform, so the
+    skew between clients is by writer, as in LEAF's FEMNIST split."""
+    c, s, wpc = n_clients, samples_per_client, writers_per_client
+    shape = tuple(cfg["input_shape"])
+    k = int(cfg["num_classes"])
+    protos = rng.standard_normal((k, *shape), dtype=np.float32)
+    styles = 0.6 * rng.standard_normal((c * wpc, *shape), dtype=np.float32)
+    y = rng.integers(0, k, size=c * s).astype(np.int32)
+    writer = (np.repeat(np.arange(c), s) * wpc
+              + np.tile(np.arange(s) % wpc, c))
+    x = protos[y]
+    x += 1.2 * rng.standard_normal(x.shape, dtype=np.float32)
+    x += styles[writer]
+    return x, y
 
 
 def kept_axes(cfg, keep):

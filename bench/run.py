@@ -51,6 +51,7 @@ class EnvironmentRefused(RuntimeError):
 
 def load_cell(name: str) -> dict:
     """The cell's entry, configuration, family, traffic, metrics, limits."""
+    from bench.harness.data import family_of
     bm = json.loads((ROOT / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bm["workloads"]}
     if name not in cells:
@@ -63,8 +64,7 @@ def load_cell(name: str) -> dict:
         return "workloads" not in m or name in m["workloads"]
     return {
         "name": name, "chips": int(cell["chips"]), "config": config,
-        "family": importlib.import_module(
-            f"bench.reference.{config['family']}"),
+        "family": family_of(config),
         "traffic": json.loads(
             (BENCH / "traffic" / f"{cell['traffic']}.json").read_text()),
         "end_to_end": [m for m in bm["end_to_end"] if used(m)],
